@@ -7,7 +7,6 @@ Prints ``name,us_per_call,derived`` CSV rows (spec format).
   Fig 8  -> fft_*            (row FFT -> corner turn -> col FFT, GFLOP/s)
   Fig 9  -> randomaccess_*   (GUPS, direct messaging)
   Fig 10 -> hpl_*            (blocked LU over block-cyclic columns)
-  +      -> kernel micro-benches (Pallas interpret-mode vs jnp oracle)
   +      -> redistribution bytes oracle (PITFALLS vs brute force)
 
 Roofline for the 40 assigned cells is separate (needs the dry-run's 512
@@ -19,47 +18,6 @@ from __future__ import annotations
 
 import sys
 import time
-
-
-def _kernel_rows() -> list[dict]:
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.kernels import attention, rmsnorm_op, triad
-
-    rows = []
-    # triad (memory-bound probe)
-    n = 1 << 20
-    b = jnp.asarray(np.random.default_rng(0).standard_normal(n), jnp.float32)
-    c = jnp.asarray(np.random.default_rng(1).standard_normal(n), jnp.float32)
-    out = triad(b, c, s=3.0)  # compile+validate
-    out.block_until_ready()
-    t0 = time.perf_counter()
-    for _ in range(3):
-        triad(b, c, s=3.0).block_until_ready()
-    dt = (time.perf_counter() - t0) / 3
-    rows.append({"name": "kernel_triad_1M", "us_per_call": dt * 1e6,
-                 "derived": f"{3*4*n/dt/2**30:.3f} GiB/s (interpret)"})
-
-    # flash attention vs oracle timing at small scale
-    q = jnp.asarray(np.random.default_rng(2).standard_normal((2, 256, 4, 64)), jnp.float32)
-    k = jnp.asarray(np.random.default_rng(3).standard_normal((2, 256, 2, 64)), jnp.float32)
-    v = jnp.asarray(np.random.default_rng(4).standard_normal((2, 256, 2, 64)), jnp.float32)
-    out = attention(q, k, v)
-    out.block_until_ready()
-    t0 = time.perf_counter()
-    attention(q, k, v).block_until_ready()
-    rows.append({"name": "kernel_flash_attn_256", "us_per_call": (time.perf_counter()-t0)*1e6,
-                 "derived": "GQA 4q/2kv heads (interpret)"})
-
-    x = jnp.asarray(np.random.default_rng(5).standard_normal((512, 2048)), jnp.float32)
-    w = jnp.zeros((2048,), jnp.float32)
-    rmsnorm_op(x, w).block_until_ready()
-    t0 = time.perf_counter()
-    rmsnorm_op(x, w).block_until_ready()
-    rows.append({"name": "kernel_rmsnorm_512x2048", "us_per_call": (time.perf_counter()-t0)*1e6,
-                 "derived": "fused reduce+scale (interpret)"})
-    return rows
 
 
 def _redistribution_rows() -> list[dict]:
@@ -92,7 +50,6 @@ def main() -> None:
         ("fft (Fig 8)", hpcc.bench_fft),
         ("randomaccess (Fig 9)", hpcc.bench_random_access),
         ("hpl (Fig 10)", hpcc.bench_hpl),
-        ("pallas kernels", _kernel_rows),
         ("pitfalls oracle", _redistribution_rows),
     ]
     print("name,us_per_call,derived")

@@ -195,8 +195,6 @@ def halo_exchange(x: jax.Array, mesh: Mesh, axis: str, dim: int, overlap: int):
 
     Works inside jit; input must be sharded over ``axis`` along ``dim``.
     """
-    from jax.experimental.shard_map import shard_map
-
     n_shards = mesh.shape[axis]
     in_spec = [None] * x.ndim
     in_spec[dim] = axis
@@ -208,7 +206,7 @@ def halo_exchange(x: jax.Array, mesh: Mesh, axis: str, dim: int, overlap: int):
         halo = jax.lax.ppermute(lead, axis, perm)  # shard i gets shard i+1's lead
         return jnp.concatenate([xl, halo], axis=dim)
 
-    return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)(x)
 
 
 def scatter_to_mesh(

@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the perf-critical hot spots (DESIGN.md §7).
 
 ``<name>.py``  — pl.pallas_call + BlockSpec VMEM tiling (TPU target)
-``ops.py``     — jitted wrappers (layout, padding, GQA, auto-interpret)
+``ops.py``     — jitted wrappers (layout, padding, GQA; ``interpret=True``
+                 runs the Pallas interpreter, as the CPU tests do)
 ``ref.py``     — pure-jnp oracles the kernels are validated against
 """
 

@@ -1,8 +1,8 @@
 """Jitted user-facing wrappers around the Pallas kernels.
 
-Handle layout/padding/GQA so callers use natural shapes; auto-select
-``interpret=True`` off-TPU (this container) so the same call validates on
-CPU and compiles natively on TPU.
+Handle layout/padding/GQA so callers use natural shapes.  Each wrapper
+compiles for the TPU unless the caller passes ``interpret=True``, which
+runs the kernel in the Pallas interpreter (how the CPU tests run it).
 """
 
 from __future__ import annotations
@@ -18,10 +18,6 @@ from .stream_triad import LANES, stream_triad as _triad
 __all__ = ["attention", "rmsnorm_op", "triad", "ssd"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def attention(
     q: jax.Array,  # (B, S, H, D)
     k: jax.Array,  # (B, S, KH, D)
@@ -29,14 +25,12 @@ def attention(
     *,
     causal: bool = True,
     blk: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """GQA flash attention with natural (B, S, H, D) layout.
 
     KV heads are broadcast to H (free at HLO level), sequence padded to
     the block size with masked-out suffix keys."""
-    if interpret is None:
-        interpret = not _on_tpu()
     b, s, h, d = q.shape
     kh = k.shape[2]
     if kh != h:
@@ -63,10 +57,8 @@ def attention(
 
 
 def rmsnorm_op(x: jax.Array, w: jax.Array, eps: float = 1e-5,
-               interpret: bool | None = None) -> jax.Array:
+               interpret: bool = False) -> jax.Array:
     """RMSNorm over the last dim of any (..., D) tensor."""
-    if interpret is None:
-        interpret = not _on_tpu()
     shape = x.shape
     m = 1
     for sdim in shape[:-1]:
@@ -81,10 +73,8 @@ def rmsnorm_op(x: jax.Array, w: jax.Array, eps: float = 1e-5,
 
 
 def triad(b: jax.Array, c: jax.Array, s: float = 3.0,
-          interpret: bool | None = None) -> jax.Array:
+          interpret: bool = False) -> jax.Array:
     """STREAM triad over flat vectors of any length (padded internally)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     n = b.shape[0]
     blk_rows = 256
     tile = blk_rows * LANES
@@ -96,12 +86,10 @@ def triad(b: jax.Array, c: jax.Array, s: float = 3.0,
 
 
 def ssd(x, dt, a_log, bm, cm, chunk: int = 64,
-        interpret: bool | None = None):
+        interpret: bool = False):
     """Mamba2 SSD with natural layouts (drop-in for models.mamba2.ssd_chunked).
 
     x: (B, S, H, P); dt: (B, S, H); a_log: (H,); bm/cm: (B, S, N)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     b, s, h, p = x.shape
     # pre-scale outside the kernel (elementwise, bandwidth-light)
     xd = (x * dt[..., None]).transpose(0, 2, 1, 3)           # (B,H,S,P)

@@ -29,7 +29,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _ssd_kernel(
     xd_ref,    # (1, 1, Q, P)  dt-scaled inputs for this (b, h, chunk)
-    cs_ref,    # (1, 1, 1, Q)  within-chunk cumulative log-decay
+    cs_ref,    # (1, 1, C, Q)  within-chunk cumulative log-decay, every chunk
     bm_ref,    # (1, Q, N)
     cm_ref,    # (1, Q, N)
     o_ref,     # (1, 1, Q, P)
@@ -44,14 +44,22 @@ def _ssd_kernel(
         state_ref[...] = jnp.zeros_like(state_ref)
 
     xd = xd_ref[0, 0].astype(jnp.float32)          # (Q, P)
-    cs = cs_ref[0, 0, 0].astype(jnp.float32)       # (Q,)
     bm = bm_ref[0].astype(jnp.float32)             # (Q, N)
     cm = cm_ref[0].astype(jnp.float32)             # (Q, N)
-
-    # intra-chunk quadratic
-    seg = cs[:, None] - cs[None, :]                # (Q, Q) i - j
+    # cs stays 2-D: this chunk's row (1, Q) and, from its diagonal, the
+    # column (Q, 1) — Mosaic lowers neither a 1-D relayout nor a value index
+    cs_row = cs_ref[0, 0, pl.ds(ci, 1), :].astype(jnp.float32)  # (1, Q)
     rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    cs_col = jnp.sum(
+        jnp.where(rows == cols, cs_row, 0.0), axis=1, keepdims=True
+    )                                              # (Q, 1)
+    cs_end = jnp.sum(
+        jnp.where(cols[:1] == q - 1, cs_row, 0.0), axis=1, keepdims=True
+    )                                              # (1, 1) = cs[Q-1]
+
+    # intra-chunk quadratic
+    seg = cs_col - cs_row                          # (Q, Q) i - j
     L = jnp.where(rows >= cols, jnp.exp(seg), 0.0)
     S = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -61,16 +69,15 @@ def _ssd_kernel(
     )  # (Q, P)
 
     # inter-chunk: contribution of the state entering this chunk
-    c_in = cm * jnp.exp(cs)[:, None]               # (Q, N)
+    c_in = cm * jnp.exp(cs_col)                    # (Q, N)
     y = y + jax.lax.dot_general(
         c_in, state_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
 
     # state update: decay to chunk end, absorb this chunk's inputs
-    decay_end = jnp.exp(cs[-1] - cs)               # (Q,)
-    b_w = bm * decay_end[:, None]                  # (Q, N)
-    new_state = state_ref[...] * jnp.exp(cs[-1]) + jax.lax.dot_general(
+    b_w = bm * jnp.exp(cs_end - cs_col)            # (Q, N)
+    new_state = state_ref[...] * jnp.exp(cs_end) + jax.lax.dot_general(
         b_w, xd, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (N, P)
     state_ref[...] = new_state
@@ -98,7 +105,10 @@ def ssd_scan(
         grid=(b, h, c),  # chunk axis innermost => sequential state carry
         in_specs=[
             pl.BlockSpec((1, 1, q, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, 1, q), lambda bi, hi, ci: (bi, hi, ci, 0)),
+            # the whole (C, Q) row block of one (b, h): a (1, Q) block would
+            # break the TPU tiling rule (last two dims divisible by (8, 128)
+            # or equal to the array's); it is fetched once per (b, h)
+            pl.BlockSpec((1, 1, c, q), lambda bi, hi, ci: (bi, hi, 0, 0)),
             pl.BlockSpec((1, q, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, q, n), lambda bi, hi, ci: (bi, ci, 0)),
         ],

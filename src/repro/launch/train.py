@@ -6,9 +6,13 @@
 On a multi-device runtime (TPU slice or forced host devices) the Dmap
 sharding rules are applied to params/optimizer/batch exactly as in the
 dry-run; on one device everything degrades to local execution.  The loop
-checkpoints every ``--ckpt-every`` steps (async), resumes from the latest
-checkpoint (``--resume``), and tolerates rank restarts: pRUN relaunches a
-dead rank, which re-enters here and resumes from the same checkpoint.
+checkpoints every ``--ckpt-every`` steps (async) and resumes from the
+latest checkpoint (``--resume``).
+
+A chip host runs ONE JAX process, which drives every chip of the host
+through the mesh: a chip belongs to the process that first touched JAX,
+so ``pRUN`` with this target at ``np>1`` on one host is not a chip path
+(the other ranks fail or hang waiting for the chip).
 """
 
 from __future__ import annotations
@@ -32,7 +36,34 @@ from ..train.checkpoint import CheckpointManager
 from ..train.data import batch_iterator
 from ..train.optimizer import AdamWConfig
 from ..train.train_step import TrainStepConfig, init_opt_state, make_train_step
+from .compile_cache import use_compile_cache
 from .mesh import make_local_mesh
+
+
+def build_train_step(cfg, *, steps: int, batch: int, lr: float = 3e-4,
+                     microbatches: int = 1, grad_compression: str = "none",
+                     mesh=None):
+    """This CLI's jitted train step: params and optimizer state donated,
+    Dmap shardings on ``mesh`` (None: one device, no shardings).
+
+    Returns ``(step_fn, ts, param_shardings, opt_state_shardings)``."""
+    p_sh = o_sh = b_sh = None
+    if mesh is not None:
+        p_sh = param_shardings(cfg, mesh)
+        o_sh = opt_state_shardings(cfg, mesh)
+        b_sh = batch_shardings(cfg, mesh, "train", batch)
+
+    opt = AdamWConfig(lr=lr, warmup_steps=min(10, steps), total_steps=steps,
+                      schedule="wsd" if cfg.wsd_schedule else "cosine")
+    ts = TrainStepConfig(microbatches=microbatches, remat=True,
+                         grad_compression=grad_compression)
+    step_fn = jax.jit(
+        make_train_step(cfg, opt, ts, grad_shardings=p_sh),
+        in_shardings=(p_sh, o_sh, b_sh) if mesh is not None else None,
+        out_shardings=(p_sh, o_sh, None) if mesh is not None else None,
+        donate_argnums=(0, 1),
+    )
+    return step_fn, ts, p_sh, o_sh
 
 
 def main(argv=None) -> int:
@@ -54,6 +85,7 @@ def main(argv=None) -> int:
                     metavar=("DATA", "MODEL"),
                     help="mesh shape over local devices")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -68,22 +100,10 @@ def main(argv=None) -> int:
         mesh = make_local_mesh(data=jax.device_count(), model=1)
     else:
         mesh = None
-    p_sh = o_sh = b_sh = None
-    if mesh is not None:
-        p_sh = param_shardings(cfg, mesh)
-        o_sh = opt_state_shardings(cfg, mesh)
-        b_sh = batch_shardings(cfg, mesh, "train", args.batch)
-
-    opt = AdamWConfig(lr=args.lr, warmup_steps=min(10, args.steps),
-                      total_steps=args.steps,
-                      schedule="wsd" if cfg.wsd_schedule else "cosine")
-    ts = TrainStepConfig(microbatches=args.microbatches, remat=True,
-                         grad_compression=args.grad_compression)
-    step_fn = jax.jit(
-        make_train_step(cfg, opt, ts, grad_shardings=p_sh),
-        in_shardings=(p_sh, o_sh, b_sh) if mesh is not None else None,
-        out_shardings=(p_sh, o_sh, None) if mesh is not None else None,
-        donate_argnums=(0, 1),
+    step_fn, ts, p_sh, o_sh = build_train_step(
+        cfg, steps=args.steps, batch=args.batch, lr=args.lr,
+        microbatches=args.microbatches,
+        grad_compression=args.grad_compression, mesh=mesh,
     )
 
     ckpt_dir = args.ckpt_dir or f"/tmp/repro_train_{cfg.name}"

@@ -6,6 +6,9 @@ Three pieces:
   dry-run cells lower (full-sequence forward; one-token decode).  With
   ``with_state=True`` the prefill step also returns the decode-state tree
   after each row's real tokens — the bulk-prefill unit.
+  ``make_admit_step`` / ``make_decode_step`` are the engine's two jitted
+  units over its carry (``init_carry``); a compile rehearsal lowers them
+  from shapes alone.
 * ``ContinuousBatchingEngine`` — fixed decode slots over a persistent
   batched decode state.  New requests are admitted into freed rows
   mid-decode by one bulk prefill forward (not ``plen`` decode steps);
@@ -176,6 +179,106 @@ def _sample(logits, temps, subkeys):
     return jnp.where(temps > 0.0, sampled, greedy)
 
 
+def init_carry(cfg: ModelConfig, slots: int, max_seq: int,
+               state_dtype=jnp.bfloat16) -> dict:
+    """The engine's persistent device state: decode state plus per-slot
+    position, budget, sampling and finished-mask vectors."""
+    return {
+        "state": init_decode_state(cfg, slots, max_seq, dtype=state_dtype),
+        "tokens": jnp.zeros((slots, 1), jnp.int32),
+        "pos": jnp.zeros((slots,), jnp.int32),
+        "active": jnp.zeros((slots,), bool),
+        "gen": jnp.zeros((slots,), jnp.int32),
+        "budget": jnp.ones((slots,), jnp.int32),
+        "temp": jnp.zeros((slots,), jnp.float32),
+        "key": jnp.zeros((slots, 2), jnp.uint32),
+        "eos": jnp.full((slots,), _NO_EOS, jnp.int32),
+    }
+
+
+def make_admit_step(cfg: ModelConfig, slots: int, state_dtype=jnp.bfloat16):
+    """The engine's admission step: one bulk prefill of the masked rows,
+    merged into the carry, plus each row's first sampled token."""
+    from ..models.model import decode_state_batch_dims
+
+    prefill = make_prefill_step(cfg, with_state=True, state_dtype=state_dtype)
+    bdims = decode_state_batch_dims(cfg)
+
+    def admit(params, carry, ptoks, plens, mask, budget, temps, keys, eos):
+        logits, pstate = prefill(
+            params, {"tokens": ptoks, "lengths": plens}
+        )
+        splits = jax.vmap(jax.random.split)(keys)  # (B, 2, 2)
+        new_keys, subs = splits[:, 0], splits[:, 1]
+        first = _sample(logits, temps, subs)
+        done0 = (first == eos) | (budget <= 1)
+
+        def merge(name, live, new):
+            new = new.astype(live.dtype)
+            if live.shape != new.shape:  # KV caches: seq pad < max_seq
+                new = jax.lax.dynamic_update_slice(
+                    live, new, (0,) * live.ndim
+                )
+            shape = [1] * live.ndim
+            shape[bdims[name]] = slots
+            return jnp.where(mask.reshape(shape), new, live)
+
+        state = {
+            n: merge(n, carry["state"][n], pstate[n]) for n in pstate
+        }
+        return {
+            "state": state,
+            "tokens": jnp.where(mask, first, carry["tokens"][:, 0])[:, None],
+            "pos": jnp.where(mask, plens, carry["pos"]),
+            "active": jnp.where(mask, ~done0, carry["active"]),
+            "gen": jnp.where(mask, 1, carry["gen"]),
+            "budget": jnp.where(mask, budget, carry["budget"]),
+            "temp": jnp.where(mask, temps, carry["temp"]),
+            "key": jnp.where(mask[:, None], new_keys, carry["key"]),
+            "eos": jnp.where(mask, eos, carry["eos"]),
+        }, jnp.stack([first, done0.astype(jnp.int32)])  # one host pull
+
+    return admit
+
+
+def make_decode_step(cfg: ModelConfig, slots: int, max_seq: int):
+    """The engine's decode step: ``(params, carry) -> (carry, (3, slots))``
+    — one token for every live row, sampled on device."""
+    moe_cap = slots * cfg.moe_top_k if cfg.family == "moe" else None
+
+    def decode(params, carry):
+        logits, state = decode_step(
+            cfg, params, carry["state"], carry["tokens"], carry["pos"],
+            moe_cap=moe_cap,
+        )
+        splits = jax.vmap(jax.random.split)(carry["key"])
+        new_keys, subs = splits[:, 0], splits[:, 1]
+        tok = _sample(logits, carry["temp"], subs)
+        was = carry["active"]
+        gen = carry["gen"] + was
+        pos = carry["pos"] + was
+        done = was & (
+            (tok == carry["eos"]) | (gen >= carry["budget"]) | (pos >= max_seq)
+        )
+        # the step's single host transfer: (3, B) int32
+        out = jnp.stack(
+            [tok, was.astype(jnp.int32), done.astype(jnp.int32)]
+        )
+        return {
+            "state": state,
+            "tokens": tok[:, None],
+            "pos": pos,
+            "active": was & ~done,
+            "gen": gen,
+            "budget": carry["budget"],
+            "temp": carry["temp"],
+            "key": new_keys,
+            "eos": carry["eos"],
+        }, out
+
+    return decode
+
+
 class ContinuousBatchingEngine:
     """Request-level continuous batching over a fixed slot batch.
 
@@ -212,17 +315,7 @@ class ContinuousBatchingEngine:
         }
         _metrics.on_reset(self.reset_stats)
 
-        self._carry = {
-            "state": init_decode_state(cfg, slots, max_seq, dtype=state_dtype),
-            "tokens": jnp.zeros((slots, 1), jnp.int32),
-            "pos": jnp.zeros((slots,), jnp.int32),
-            "active": jnp.zeros((slots,), bool),
-            "gen": jnp.zeros((slots,), jnp.int32),
-            "budget": jnp.ones((slots,), jnp.int32),
-            "temp": jnp.zeros((slots,), jnp.float32),
-            "key": jnp.zeros((slots, 2), jnp.uint32),
-            "eos": jnp.full((slots,), _NO_EOS, jnp.int32),
-        }
+        self._carry = init_carry(cfg, slots, max_seq, state_dtype)
         if mesh is not None:
             from ..dist.sharding import serve_carry_shardings
 
@@ -231,93 +324,12 @@ class ContinuousBatchingEngine:
                 serve_carry_shardings(cfg, mesh, slots, max_seq),
             )
 
-        prefill = make_prefill_step(cfg, with_state=True, state_dtype=state_dtype)
         self._admit_fn = jax.jit(
-            self._build_admit(prefill), donate_argnums=(1,)
+            make_admit_step(cfg, slots, state_dtype), donate_argnums=(1,)
         )
-        self._decode_fn = jax.jit(self._build_decode(), donate_argnums=(1,))
-
-    # -- jitted steps ------------------------------------------------------
-
-    def _build_admit(self, prefill):
-        cfg = self.cfg
-        from ..models.model import decode_state_batch_dims
-
-        bdims = decode_state_batch_dims(cfg)
-        slots = self.slots
-
-        def admit(params, carry, ptoks, plens, mask, budget, temps, keys, eos):
-            logits, pstate = prefill(
-                params, {"tokens": ptoks, "lengths": plens}
-            )
-            splits = jax.vmap(jax.random.split)(keys)  # (B, 2, 2)
-            new_keys, subs = splits[:, 0], splits[:, 1]
-            first = _sample(logits, temps, subs)
-            done0 = (first == eos) | (budget <= 1)
-
-            def merge(name, live, new):
-                new = new.astype(live.dtype)
-                if live.shape != new.shape:  # KV caches: seq pad < max_seq
-                    new = jax.lax.dynamic_update_slice(
-                        live, new, (0,) * live.ndim
-                    )
-                shape = [1] * live.ndim
-                shape[bdims[name]] = slots
-                return jnp.where(mask.reshape(shape), new, live)
-
-            state = {
-                n: merge(n, carry["state"][n], pstate[n]) for n in pstate
-            }
-            return {
-                "state": state,
-                "tokens": jnp.where(mask, first, carry["tokens"][:, 0])[:, None],
-                "pos": jnp.where(mask, plens, carry["pos"]),
-                "active": jnp.where(mask, ~done0, carry["active"]),
-                "gen": jnp.where(mask, 1, carry["gen"]),
-                "budget": jnp.where(mask, budget, carry["budget"]),
-                "temp": jnp.where(mask, temps, carry["temp"]),
-                "key": jnp.where(mask[:, None], new_keys, carry["key"]),
-                "eos": jnp.where(mask, eos, carry["eos"]),
-            }, jnp.stack([first, done0.astype(jnp.int32)])  # one host pull
-
-        return admit
-
-    def _build_decode(self):
-        cfg = self.cfg
-        max_seq = self.max_seq
-        moe_cap = self.slots * cfg.moe_top_k if cfg.family == "moe" else None
-
-        def decode(params, carry):
-            logits, state = decode_step(
-                cfg, params, carry["state"], carry["tokens"], carry["pos"],
-                moe_cap=moe_cap,
-            )
-            splits = jax.vmap(jax.random.split)(carry["key"])
-            new_keys, subs = splits[:, 0], splits[:, 1]
-            tok = _sample(logits, carry["temp"], subs)
-            was = carry["active"]
-            gen = carry["gen"] + was
-            pos = carry["pos"] + was
-            done = was & (
-                (tok == carry["eos"]) | (gen >= carry["budget"]) | (pos >= max_seq)
-            )
-            # the step's single host transfer: (3, B) int32
-            out = jnp.stack(
-                [tok, was.astype(jnp.int32), done.astype(jnp.int32)]
-            )
-            return {
-                "state": state,
-                "tokens": tok[:, None],
-                "pos": pos,
-                "active": was & ~done,
-                "gen": gen,
-                "budget": carry["budget"],
-                "temp": carry["temp"],
-                "key": new_keys,
-                "eos": carry["eos"],
-            }, out
-
-        return decode
+        self._decode_fn = jax.jit(
+            make_decode_step(cfg, slots, max_seq), donate_argnums=(1,)
+        )
 
     # -- host control loop -------------------------------------------------
 
