@@ -215,8 +215,8 @@ def main() -> int:
          "tokens": b_tokens, "wall_s": b_wall},
         {"name": "ttft", "p50_ms": stats.get("ttft_p50_ms"),
          "p95_ms": stats.get("ttft_p95_ms")},
-        {"name": "per_token_latency", "p50_ms": stats.get("tpot_p50_ms"),
-         "p95_ms": stats.get("tpot_p95_ms")},
+        {"name": "itl", "p50_ms": stats.get("itl_p50_ms"),
+         "p95_ms": stats.get("itl_p95_ms")},
         {"name": "slot_occupancy",
          "padded_slot_waste": stats["padded_slot_waste"],
          "prefill_steps": stats["prefill_steps"],

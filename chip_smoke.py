@@ -81,11 +81,9 @@ FIELD_N = 16384        # side of the float32 field the 4-chip bridge moves
 # the batch mean and the matmul tiling split differently across chips
 LOSS_RTOL = 1e-3
 
-_COMPILE_EVENTS = (
-    "/jax/core/compile/jaxpr_trace_duration",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration",
-    "/jax/core/compile/backend_compile_duration",
-)
+# repro.obs's spans of JAX's stages: tracing, lowering, compiling (or
+# loading from the persistent cache)
+_COMPILE_SPANS = ("jax.trace", "jax.lower", "jax.compile")
 
 
 def check(cond, msg: str) -> None:
@@ -96,7 +94,8 @@ def check(cond, msg: str) -> None:
 class Report:
     """JSON lines on stdout, each labelled with the device kind.
 
-    ``listen()`` collects, from JAX's monitoring events, the seconds spent
+    ``listen()`` turns ``repro.obs`` tracing on; ``collect()`` folds the
+    compile spans it recorded since the last call into the seconds spent
     tracing, lowering and compiling (or loading from the persistent
     cache) per jitted function name, and the persistent cache's hits and
     misses."""
@@ -107,16 +106,16 @@ class Report:
         self.cache_events: collections.Counter = collections.Counter()
 
     def listen(self) -> None:
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
+        trace.enable_trace()
 
-    def _on_duration(self, event, secs, fun_name="?", **_):
-        if event in _COMPILE_EVENTS:  # tracing names "f", the rest "jit(f)"
-            self.compile_s[fun_name.removeprefix("jit(").removesuffix(")")] += secs
-
-    def _on_event(self, event, **_):
-        if event.startswith("/jax/compilation_cache/"):
-            self.cache_events[event.rsplit("/", 1)[1]] += 1
+    def collect(self) -> None:
+        for name, _ph, _ts, dur, attrs in trace.events():
+            if name in _COMPILE_SPANS:  # tracing names "f", the rest "jit(f)"
+                fun = attrs["fun_name"].removeprefix("jit(").removesuffix(")")
+                self.compile_s[fun] += dur
+                if "cache" in attrs:
+                    self.cache_events[f"cache_{attrs['cache']}s"] += 1
+        trace.reset_trace()
 
     def emit(self, **fields) -> None:
         print(json.dumps({"device_kind": self.kind, **fields}), flush=True)
@@ -124,6 +123,7 @@ class Report:
     def step(self, phase: str, name: str, walls: list[float], **extra) -> None:
         """Compile seconds, the first call (compile included) and the
         steady calls after it, all wall seconds to a synchronized result."""
+        self.collect()
         rest = walls[1:]
         self.emit(
             phase=phase, step=name, compile_s=self.compile_s.get(name, 0.0),
@@ -309,6 +309,7 @@ def main(argv=None) -> int:
         dp_train_phase(rep, train_cfg, args.seed, TRAIN["batch"], TRAIN["seq"],
                        TRAIN["steps"], args.chips)
         rep.memory("dp_train")
+    rep.collect()
     rep.emit(phase="compile_cache", **rep.cache_events)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

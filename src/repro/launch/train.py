@@ -129,13 +129,17 @@ def main(argv=None) -> int:
                                           start_step=start):
             if step >= args.steps:
                 break
+            # train.step times the dispatch; train.wait the pull of the
+            # loss, which waits for the chip to finish the step
             with _trace.span("train.step", step=step,
                              tokens=args.batch * args.seq):
                 params, opt_state, metrics = step_fn(params, opt_state, batch)
             if step % 5 == 0 or step == args.steps - 1:
-                print(f"[train] step {step:4d} loss {float(metrics['loss']):8.4f} "
-                      f"lr {float(metrics['lr']):.2e} "
-                      f"gnorm {float(metrics['grad_norm']):.2f}", flush=True)
+                with _trace.span("train.wait", step=step):
+                    m = jax.device_get(metrics)
+                print(f"[train] step {step:4d} loss {float(m['loss']):8.4f} "
+                      f"lr {float(m['lr']):.2e} "
+                      f"gnorm {float(m['grad_norm']):.2f}", flush=True)
             if step and step % args.ckpt_every == 0:
                 mgr.save(step, {"params": params, "opt_state": opt_state},
                          blocking=False)

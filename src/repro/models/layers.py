@@ -124,6 +124,7 @@ def _qkv(cfg: ModelConfig, p, x, positions):
     return q, k, v
 
 
+@jax.named_scope("attention")
 def attention(cfg: ModelConfig, p, x, positions, mask=None):
     """Causal attention; switches to the chunked online-softmax path for
     long sequences (the jnp mirror of the Pallas flash kernel)."""
@@ -145,6 +146,7 @@ def attention(cfg: ModelConfig, p, x, positions, mask=None):
     return out @ p["wo"]
 
 
+@jax.named_scope("attention")
 def attention_prefill(cfg: ModelConfig, p, x, positions):
     """Causal attention that also returns the rotated *pre-repeat* K/V —
     exactly the rows ``attention_decode`` would have appended to its
@@ -230,30 +232,12 @@ def attention_chunked(cfg: ModelConfig, p, x, positions, blk: int = 2048):
     return out @ p["wo"]
 
 
-def attention_decode(cfg: ModelConfig, p, x, cache_k, cache_v, pos):
-    """One-token decode against a KV cache.
-
-    x: (B, 1, D); cache_k/v: (B, S_max, KH, Dh); pos: () current index, or
-    (B,) per-row positions (continuous batching: every serve slot decodes
-    at its own depth).  Returns (out, new_k, new_v)."""
-    b = x.shape[0]
-    h, kh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    pos = jnp.asarray(pos, dtype=jnp.int32)
-    per_row = pos.ndim == 1
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].reshape(cfg.d_model, h, dh))
-    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].reshape(cfg.d_model, kh, dh))
-    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].reshape(cfg.d_model, kh, dh))
-    if cfg.qkv_bias:
-        q = q + p["bq"].reshape(h, dh)
-        k = k + p["bk"].reshape(kh, dh)
-        v = v + p["bv"].reshape(kh, dh)
-    posb = pos[:, None] if per_row else jnp.full((b, 1), pos, dtype=jnp.int32)
-    if cfg.pos_embedding == "mrope":
-        posb = jnp.broadcast_to(posb[None], (3, b, 1))
-    q = _rotate(cfg, q, posb)
-    k = _rotate(cfg, k, posb)
-
-    if per_row:
+@jax.named_scope("kv_write")
+def _kv_write(cfg: ModelConfig, cache_k, cache_v, k, v, pos):
+    """This step's K/V rows written into the (B, S_max, KH, Dh) cache at
+    ``pos`` (a scalar, or one position per row)."""
+    h, kh = cfg.n_heads, cfg.n_kv_heads
+    if pos.ndim == 1:
         # per-slot positions: each row writes its own cache index — a
         # batched dynamic_update_slice does not exist, the row-wise
         # iota-select is the batched form of the GQA path below
@@ -281,6 +265,34 @@ def attention_decode(cfg: ModelConfig, p, x, cache_k, cache_v, pos):
         cache_v = jax.lax.dynamic_update_slice_in_dim(
             cache_v, v.astype(cache_v.dtype), pos, axis=1
         )
+    return cache_k, cache_v
+
+
+@jax.named_scope("attention")
+def attention_decode(cfg: ModelConfig, p, x, cache_k, cache_v, pos):
+    """One-token decode against a KV cache.
+
+    x: (B, 1, D); cache_k/v: (B, S_max, KH, Dh); pos: () current index, or
+    (B,) per-row positions (continuous batching: every serve slot decodes
+    at its own depth).  Returns (out, new_k, new_v)."""
+    b = x.shape[0]
+    h, kh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    pos = jnp.asarray(pos, dtype=jnp.int32)
+    per_row = pos.ndim == 1
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].reshape(cfg.d_model, h, dh))
+    k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].reshape(cfg.d_model, kh, dh))
+    v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].reshape(cfg.d_model, kh, dh))
+    if cfg.qkv_bias:
+        q = q + p["bq"].reshape(h, dh)
+        k = k + p["bk"].reshape(kh, dh)
+        v = v + p["bv"].reshape(kh, dh)
+    posb = pos[:, None] if per_row else jnp.full((b, 1), pos, dtype=jnp.int32)
+    if cfg.pos_embedding == "mrope":
+        posb = jnp.broadcast_to(posb[None], (3, b, 1))
+    q = _rotate(cfg, q, posb)
+    k = _rotate(cfg, k, posb)
+
+    cache_k, cache_v = _kv_write(cfg, cache_k, cache_v, k, v, pos)
 
     # grouped-query einsum: repeating KV heads (broadcast_in_dim) made
     # GSPMD all-gather the seq-sharded cache every layer (90% of decode
@@ -319,6 +331,7 @@ def _act(cfg_act: str, x):
     raise ValueError(f"unknown activation {cfg_act}")
 
 
+@jax.named_scope("mlp")
 def ffn(cfg: ModelConfig, p, x):
     """Gated (GLU) or plain FFN, by activation name."""
     if cfg.activation.endswith("_glu"):

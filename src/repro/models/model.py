@@ -266,7 +266,8 @@ def model_forward(
     head = (
         params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     )
-    logits = (x @ head).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = (x @ head).astype(jnp.float32)
     return logits, aux
 
 
@@ -472,10 +473,11 @@ def prefill_forward(cfg: ModelConfig, params, tokens, lengths,
 
     xl = rms_norm(row_last(x), params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (xl @ head).astype(jnp.float32)
-    if cfg.vocab_padded != cfg.vocab:
-        pad_mask = jnp.arange(cfg.vocab_padded) >= cfg.vocab
-        logits = jnp.where(pad_mask, -1e30, logits)
+    with jax.named_scope("lm_head"):
+        logits = (xl @ head).astype(jnp.float32)
+        if cfg.vocab_padded != cfg.vocab:
+            pad_mask = jnp.arange(cfg.vocab_padded) >= cfg.vocab
+            logits = jnp.where(pad_mask, -1e30, logits)
     return logits, state
 
 
@@ -568,8 +570,9 @@ def decode_step(cfg: ModelConfig, params, state, tokens, pos, moe_cap=None):
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x[:, 0] @ head).astype(jnp.float32)
-    if cfg.vocab_padded != cfg.vocab:
-        pad_mask = jnp.arange(cfg.vocab_padded) >= cfg.vocab
-        logits = jnp.where(pad_mask, -1e30, logits)
+    with jax.named_scope("lm_head"):
+        logits = (x[:, 0] @ head).astype(jnp.float32)
+        if cfg.vocab_padded != cfg.vocab:
+            pad_mask = jnp.arange(cfg.vocab_padded) >= cfg.vocab
+            logits = jnp.where(pad_mask, -1e30, logits)
     return logits, new_state

@@ -12,6 +12,18 @@ Design contract (the hot-path side of the ISSUE):
   ``PPYTHON_TRACE_BUF``, default 65536) under a lock — overwrite-oldest,
   never grow, never block the caller on I/O.  Timestamps are
   ``time.perf_counter()`` (monotonic).
+* One clock with the device: where JAX was already imported when
+  ``enable_trace()`` ran, every ``span()`` also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so while a profiler
+  trace is active it shows on the trace's host plane beside the device's
+  operations.
+* Runtime spans, installed by ``enable_trace()`` and removed by
+  ``disable_trace()``: ``py.gc`` for every Python garbage collection
+  (``generation``, ``collected``; also annotated), and, where JAX is
+  imported, ``jax.trace`` / ``jax.lower`` / ``jax.compile`` from its
+  ``jax.monitoring`` duration events (``fun_name``; ``jax.compile`` is a
+  backend compile or a persistent-cache load, ``cache`` "hit" or "miss"
+  where the persistent cache was consulted), stamped end minus duration.
 * ``merge_traces(ctx)`` runs at the end of a traced pRUN job: rank 0
   estimates each peer's clock offset with a ping handshake (midpoint
   method, best-of-N by RTT), gathers every rank's buffer over the
@@ -23,8 +35,10 @@ Stdlib-only on purpose (comm imports this; workers must start fast).
 
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import threading
 import time
 from pathlib import Path
@@ -34,6 +48,7 @@ __all__ = [
     "enabled",
     "span",
     "instant",
+    "complete",
     "enable_trace",
     "disable_trace",
     "reset_trace",
@@ -51,6 +66,10 @@ DEFAULT_CAPACITY = 65536
 enabled: bool = False
 
 _tracer: "_Tracer | None" = None
+
+#: ``jax.profiler.TraceAnnotation`` while tracing is on in a process that
+#: had imported JAX, else None.
+_annotation = None
 
 
 def _env_flag(name: str, default: str = "0") -> bool:
@@ -79,7 +98,9 @@ class _Tracer:
         self.capacity = capacity
         self.buf: list[tuple | None] = [None] * capacity
         self.n = 0
-        self.lock = threading.Lock()
+        # reentrant: a GC pass can start inside ``record`` and record
+        # its ``py.gc`` span from the same thread
+        self.lock = threading.RLock()
         self.t_start = time.perf_counter()
 
     def record(self, name: str, ph: str, ts: float, dur: float,
@@ -106,7 +127,7 @@ class _Span:
     """Recording context manager: measures wall time, stores one "X"
     event at exit.  ``set(**attrs)`` adds attributes mid-flight."""
 
-    __slots__ = ("_name", "_attrs", "_t0")
+    __slots__ = ("_name", "_attrs", "_t0", "_ann")
 
     def __init__(self, name: str, attrs: dict) -> None:
         self._name = name
@@ -117,11 +138,18 @@ class _Span:
         return self
 
     def __enter__(self) -> "_Span":
+        ann = _annotation
+        if ann is not None:
+            ann = ann(self._name)
+            ann.__enter__()
+        self._ann = ann
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         tr = _tracer
         if tr is not None:
             tr.record(self._name, "X", self._t0, t1 - self._t0, self._attrs)
@@ -164,20 +192,119 @@ def instant(name: str, **attrs: Any) -> None:
         tr.record(name, "i", time.perf_counter(), 0.0, attrs or None)
 
 
+def complete(name: str, ts: float, dur: float, **attrs: Any) -> None:
+    """Record a span that has already ended: ``ts`` on the tracer's
+    clock (``time.perf_counter()``), ``dur`` in seconds."""
+    if not enabled:
+        return
+    tr = _tracer
+    if tr is not None:
+        tr.record(name, "X", ts, dur, attrs)
+
+
+# ---------------------------------------------------------------------------
+# Runtime spans: Python GC passes and JAX compilations
+# ---------------------------------------------------------------------------
+
+_gc_start: tuple | None = None  # (t0, annotation) of the running collection
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_start
+    if phase == "start":
+        if not enabled:
+            return
+        ann = _annotation
+        if ann is not None:
+            ann = ann("py.gc")
+            ann.__enter__()
+        _gc_start = (time.perf_counter(), ann)
+        return
+    if _gc_start is None:
+        return
+    t0, ann = _gc_start
+    _gc_start = None
+    t1 = time.perf_counter()
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    complete("py.gc", t0, t1 - t0, generation=info["generation"],
+             collected=info["collected"])
+
+
+_JAX_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_cache_seen = threading.local()  # the persistent cache's verdict, per thread
+
+
+def _on_jax_duration(event: str, secs: float, fun_name: str = "?",
+                     **_: Any) -> None:
+    name = _JAX_STAGES.get(event)
+    if name is None:
+        return
+    attrs = {"fun_name": fun_name}
+    if name == "jax.compile":
+        cache = getattr(_cache_seen, "verdict", None)
+        _cache_seen.verdict = None
+        if cache is not None:
+            attrs["cache"] = cache
+    complete(name, time.perf_counter() - secs, secs, **attrs)
+
+
+def _on_jax_event(event: str, **_: Any) -> None:
+    verdict = _CACHE_EVENTS.get(event)
+    if verdict is not None:
+        _cache_seen.verdict = verdict
+
+
+def _install_runtime_hooks() -> None:
+    global _annotation
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    jax = sys.modules.get("jax")
+    if jax is None or _annotation is not None:
+        return
+    _annotation = jax.profiler.TraceAnnotation
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    jax.monitoring.register_event_listener(_on_jax_event)
+
+
+def _remove_runtime_hooks() -> None:
+    global _annotation
+    if _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+    if _annotation is None:
+        return
+    _annotation = None
+    monitoring = sys.modules["jax"].monitoring
+    monitoring.unregister_event_duration_listener(_on_jax_duration)
+    monitoring.unregister_event_listener(_on_jax_event)
+
+
 def enable_trace(capacity: int | None = None) -> None:
-    """Turn tracing on (idempotent); allocates the ring buffer."""
+    """Turn tracing on (idempotent); allocates the ring buffer and
+    installs the runtime spans (JAX's only if JAX is already imported)."""
     global enabled, _tracer
     if capacity is None:
         capacity = _env_capacity()
     if _tracer is None or _tracer.capacity != capacity:
         _tracer = _Tracer(capacity)
+    _install_runtime_hooks()
     enabled = True
 
 
 def disable_trace() -> None:
-    """Turn tracing off; the buffer (and its events) survive."""
+    """Turn tracing off and remove the runtime hooks; the buffer (and
+    its events) survive."""
     global enabled
     enabled = False
+    _remove_runtime_hooks()
 
 
 def reset_trace() -> None:

@@ -204,6 +204,7 @@ def make_admit_step(cfg: ModelConfig, slots: int, state_dtype=jnp.bfloat16):
     prefill = make_prefill_step(cfg, with_state=True, state_dtype=state_dtype)
     bdims = decode_state_batch_dims(cfg)
 
+    @jax.named_scope("admit")
     def admit(params, carry, ptoks, plens, mask, budget, temps, keys, eos):
         logits, pstate = prefill(
             params, {"tokens": ptoks, "lengths": plens}
@@ -223,9 +224,10 @@ def make_admit_step(cfg: ModelConfig, slots: int, state_dtype=jnp.bfloat16):
             shape[bdims[name]] = slots
             return jnp.where(mask.reshape(shape), new, live)
 
-        state = {
-            n: merge(n, carry["state"][n], pstate[n]) for n in pstate
-        }
+        with jax.named_scope("kv_write"):
+            state = {
+                n: merge(n, carry["state"][n], pstate[n]) for n in pstate
+            }
         return {
             "state": state,
             "tokens": jnp.where(mask, first, carry["tokens"][:, 0])[:, None],
@@ -246,6 +248,7 @@ def make_decode_step(cfg: ModelConfig, slots: int, max_seq: int):
     — one token for every live row, sampled on device."""
     moe_cap = slots * cfg.moe_top_k if cfg.family == "moe" else None
 
+    @jax.named_scope("decode")
     def decode(params, carry):
         logits, state = decode_step(
             cfg, params, carry["state"], carry["tokens"], carry["pos"],
@@ -309,7 +312,7 @@ class ContinuousBatchingEngine:
         # and metrics.reset() clears them via the registered hook
         scope = f"serve.e{next(_ENGINE_IDS)}."
         self._ttft = _metrics.histogram(scope + "ttft_s")
-        self._tpot = _metrics.histogram(scope + "tpot_s")
+        self._itl = _metrics.histogram(scope + "itl_s")
         self._counters = {
             name: _metrics.counter(scope + name) for name in _COUNTER_NAMES
         }
@@ -378,12 +381,14 @@ class ContinuousBatchingEngine:
             keys[s] = self._seed_key(req.seed)
             eos[s] = _NO_EOS if req.eos_id is None else req.eos_id
         t0 = self.clock()
-        with _trace.span("serve.prefill", rows=len(plan), pad=P):
+        with _trace.span("serve.prefill", rows=len(plan), pad=P,
+                         rids=[req.rid for _, req in plan]):
             self._carry, packed = self._admit_fn(
                 self.params, self._carry, ptoks, plens, mask, budget, temps,
                 keys, eos,
             )
-            packed = np.asarray(packed)  # one sync
+            with _trace.span("serve.prefill.wait"):
+                packed = np.asarray(packed)  # one sync
         first, done0 = packed[0], packed[1].astype(bool)
         t1 = self.clock()
         self._counters["prefill_steps"].inc()
@@ -392,11 +397,12 @@ class ContinuousBatchingEngine:
             req.admit_t = t0
             req.first_token_t = t1
             req.tokens.append(int(first[s]))
+            req.token_t.append(t1)
             self._counters["tokens_generated"].inc()
             self._ttft.observe(t1 - req.arrival_t)
-            if _trace.enabled:
-                _trace.instant("serve.ttft", rid=req.rid, slot=s,
-                               ttft_ms=(t1 - req.arrival_t) * 1e3)
+            # TTFT = serve.queue + serve.prefill
+            _trace.complete("serve.queue", req.arrival_t, t0 - req.arrival_t,
+                            rid=req.rid)
             if done0[s]:
                 req.finish_t = t1
                 finished.append(self.sched.retire(s))
@@ -410,10 +416,10 @@ class ContinuousBatchingEngine:
         return k
 
     def _do_decode(self, finished):
-        t0 = self.clock()
         with _trace.span("serve.decode", slots=self.slots) as sp:
             self._carry, packed = self._decode_fn(self.params, self._carry)
-            packed = np.asarray(packed)  # one sync
+            with _trace.span("serve.decode.wait"):
+                packed = np.asarray(packed)  # one sync
             tok, was, done = (packed[0], packed[1].astype(bool),
                               packed[2].astype(bool))
             sp.set(active=int(was.sum()))
@@ -425,6 +431,8 @@ class ContinuousBatchingEngine:
             n_active += 1
             req = self.sched.slots[s]
             req.tokens.append(int(tok[s]))
+            self._itl.observe(t1 - req.token_t[-1])
+            req.token_t.append(t1)
             self._counters["tokens_generated"].inc()
             if done[s]:
                 req.finish_t = t1
@@ -432,21 +440,23 @@ class ContinuousBatchingEngine:
         self._counters["decode_steps"].inc()
         self._counters["slot_steps_total"].inc(self.slots)
         self._counters["slot_steps_active"].inc(n_active)
-        if n_active:
-            self._tpot.observe((t1 - t0) / n_active)
 
     def step(self) -> list[Request]:
         """One engine step: admission prefill (if warranted) then one
-        batched decode step.  Returns requests that finished."""
+        batched decode step.  Returns requests that finished.
+
+        Traced (``repro.obs``), ``serve.step`` spans the whole step; its
+        time outside ``serve.prefill.wait`` and ``serve.decode.wait`` is
+        the host's own (planning, packing, token bookkeeping)."""
         finished: list[Request] = []
-        plan = self.sched.plan_admissions()
-        if plan:
-            if _trace.enabled:
-                _trace.instant("serve.admit_group", rows=len(plan),
-                               queued=len(self.sched.queue))
-            self._do_admit(plan, finished)
-        if self.sched.active_slots():
-            self._do_decode(finished)
+        with _trace.span("serve.step"):
+            with _trace.span("serve.plan") as sp:
+                plan = self.sched.plan_admissions()
+                sp.set(rows=len(plan), queued=len(self.sched.queue))
+            if plan:
+                self._do_admit(plan, finished)
+            if self.sched.active_slots():
+                self._do_decode(finished)
         return finished
 
     def run(self) -> list[Request]:
@@ -462,7 +472,7 @@ class ContinuousBatchingEngine:
         are untouched.  Also runs as an ``obs.metrics.reset()`` hook so
         one registry-wide reset clears engine state too."""
         self._ttft.reset()
-        self._tpot.reset()
+        self._itl.reset()
         for c in self._counters.values():
             c.reset()
         for k in self.sched.counters:
@@ -476,7 +486,7 @@ class ContinuousBatchingEngine:
         stats.update({k: c.value for k, c in self._counters.items()})
         total = max(1, stats["slot_steps_total"])
         stats["padded_slot_waste"] = 1.0 - stats["slot_steps_active"] / total
-        for name, h in (("ttft", self._ttft), ("tpot", self._tpot)):
+        for name, h in (("ttft", self._ttft), ("itl", self._itl)):
             xs = h.samples()
             if xs:
                 stats[f"{name}_p50_ms"] = float(np.percentile(xs, 50) * 1e3)

@@ -34,6 +34,9 @@ class Request:
 
     ``tokens`` holds only the *generated* tokens (the prompt is not
     echoed); timestamps are engine-clock floats, -1.0 until reached.
+    ``token_t[i]`` is when the step that made ``tokens[i]`` synced (the
+    first at ``first_token_t``), so its differences are the inter-token
+    latencies.
     """
 
     rid: int
@@ -47,6 +50,7 @@ class Request:
     first_token_t: float = -1.0
     finish_t: float = -1.0
     tokens: list[int] = field(default_factory=list)
+    token_t: list[float] = field(default_factory=list)
 
     @property
     def ttft(self) -> float:

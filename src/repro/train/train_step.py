@@ -78,6 +78,7 @@ def make_train_step(
         )(params)
         return loss, _pin(g)
 
+    @jax.named_scope("train_step")
     def train_step(params, opt_state, batch):
         if ts.microbatches > 1:
             # unrolled gradient accumulation: each add updates the fp32

@@ -8,22 +8,24 @@ import subprocess
 import sys
 from pathlib import Path
 
-import jax
 import pytest
 
 import chip_smoke
 from repro.configs import get_config
+from repro.obs import trace
 
 ROOT = Path(chip_smoke.__file__).resolve().parent
 
 
 @pytest.fixture
 def rep():
+    was = trace.enabled
     r = chip_smoke.Report("cpu")
     r.listen()
     yield r
-    jax.monitoring.unregister_event_duration_listener(r._on_duration)
-    jax.monitoring.unregister_event_listener(r._on_event)
+    if not was:
+        trace.disable_trace()
+    trace.reset_trace()
 
 
 def _tiny():
@@ -49,10 +51,14 @@ def test_serve_phase_tiny(rep, capsys):
 
 
 def test_compile_seconds_join_every_stage_of_one_function(rep):
-    for event in chip_smoke._COMPILE_EVENTS:
-        rep._on_duration(event, 1.0, fun_name="jit(admit)")
-    rep._on_duration(chip_smoke._COMPILE_EVENTS[0], 1.0, fun_name="admit")
+    trace.reset_trace()
+    for name in chip_smoke._COMPILE_SPANS:
+        trace.complete(name, 0.0, 1.0, fun_name="jit(admit)")
+    trace.complete(chip_smoke._COMPILE_SPANS[0], 0.0, 1.0, fun_name="admit")
+    trace.complete("jax.compile", 0.0, 1.0, fun_name="jit(decode)", cache="hit")
+    rep.collect()
     assert rep.compile_s["admit"] == 4.0
+    assert rep.cache_events == {"cache_hits": 1}
 
 
 def test_train_phase_tiny(rep):

@@ -25,10 +25,14 @@ from repro.obs import trace as tr
 
 @pytest.fixture(autouse=True)
 def _clean_trace_state():
-    """Tests toggle the module-level flag; restore the disabled default."""
+    """Tests toggle the module-level flag; restore the disabled default
+    (and with it, no runtime hooks installed)."""
     was = tr.enabled
     yield
-    tr.enabled = was
+    if was:
+        tr.enable_trace()
+    else:
+        tr.disable_trace()
     tr.reset_trace()
 
 
@@ -216,6 +220,15 @@ class TestTracer:
             f"pingpong iteration (contract: <5%)"
         )
 
+    def test_complete_records_an_ended_span(self):
+        tr.enable_trace(capacity=64)
+        tr.reset_trace()
+        tr.complete("op.done", 1.5, 0.25, rid=4)
+        assert tr.events() == [("op.done", "X", 1.5, 0.25, {"rid": 4})]
+        tr.disable_trace()
+        tr.complete("op.done", 2.0, 0.1)
+        assert len(tr.events()) == 1
+
     def test_instrument_context_noop_when_disabled(self):
         tr.disable_trace()
 
@@ -232,6 +245,182 @@ class TestTracer:
         # hit the exact original bound methods
         assert "send" not in vars(d) and "recv" not in vars(d)
         assert not getattr(d, "_obs_instrumented", False)
+
+
+# ---------------------------------------------------------------------------
+# serve-engine and runtime spans
+# ---------------------------------------------------------------------------
+
+
+def _within(inner, outer) -> bool:
+    """Event ``inner`` lies inside some event of ``outer`` in time."""
+    _, _, ts, dur, _ = inner
+    return any(o[2] <= ts and ts + dur <= o[2] + o[3] for o in outer)
+
+
+def _jax_listeners():
+    from jax._src import monitoring
+
+    return (monitoring.get_event_duration_listeners()
+            + monitoring.get_event_listeners())
+
+
+class TestEngineAndRuntimeSpans:
+    @pytest.fixture(scope="class")
+    def engine(self):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.configs import get_config
+        from repro.models import init_params
+        from repro.serve import ContinuousBatchingEngine
+
+        cfg = get_config("minicpm-2b").reduced()
+        params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+        eng = ContinuousBatchingEngine(cfg, params, slots=2, max_seq=32,
+                                       prefill_pad=8, state_dtype=jnp.float32)
+        eng.submit([1, 2], max_new=2)
+        eng.run()  # compiled before any test records
+        return eng
+
+    def _serve(self, eng):
+        reqs = [eng.submit([3, 4, 5], max_new=4), eng.submit([6], max_new=3)]
+        eng.run()
+        return reqs
+
+    def test_engine_spans_nest_at_each_layer_boundary(self, engine):
+        tr.enable_trace(capacity=4096)
+        tr.reset_trace()
+        self._serve(engine)
+        by = {}
+        for e in tr.events():
+            by.setdefault(e[0], []).append(e)
+        steps = by["serve.step"]
+        assert len(steps) == len(by["serve.plan"]) >= 3
+        assert all(_within(e, steps) for e in by["serve.plan"])
+        assert all(_within(e, by["serve.prefill"]) for e in by["serve.prefill.wait"])
+        assert all(_within(e, by["serve.decode"]) for e in by["serve.decode.wait"])
+        assert len(by["serve.prefill.wait"]) == len(by["serve.prefill"]) >= 1
+        assert len(by["serve.decode.wait"]) == len(by["serve.decode"]) >= 3
+        assert all(_within(e, steps)
+                   for n in ("serve.prefill", "serve.decode") for e in by[n])
+        assert {"rows", "queued"} <= set(by["serve.plan"][0][4])
+
+    def test_queue_span_carries_its_request(self, engine):
+        tr.enable_trace(capacity=4096)
+        tr.reset_trace()
+        reqs = self._serve(engine)
+        evs = tr.events()
+        queue = {e[4]["rid"]: e for e in evs if e[0] == "serve.queue"}
+        assert set(queue) == {r.rid for r in reqs}
+        for r in reqs:
+            _, _, ts, dur, _ = queue[r.rid]
+            assert ts == r.arrival_t and ts + dur == pytest.approx(r.admit_t)
+        admitted = [rid for e in evs if e[0] == "serve.prefill"
+                    for rid in e[4]["rids"]]
+        assert sorted(admitted) == sorted(queue)
+
+    def test_gc_pass_records_one_span(self):
+        import gc
+
+        tr.enable_trace(capacity=64)
+        tr.reset_trace()
+        gc.disable()
+        try:
+            gc.collect()
+        finally:
+            gc.enable()
+        (ev,) = [e for e in tr.events() if e[0] == "py.gc"]
+        assert ev[1] == "X" and ev[3] >= 0
+        assert ev[4]["generation"] == 2 and ev[4]["collected"] >= 0
+
+    def test_gc_pass_inside_record_does_not_deadlock(self):
+        """A GC pass can start while ``record`` holds the ring's lock and
+        record its own span from the same thread.  In a child with a
+        timeout, so a regression fails instead of hanging the suite."""
+        import subprocess
+        import sys
+
+        code = (
+            "import gc\n"
+            "from repro.obs import trace as tr\n"
+            "tr.enable_trace(capacity=1 << 16)\n"
+            "gc.set_threshold(1, 1, 1)\n"
+            "junk = []\n"
+            "for i in range(20000):\n"
+            "    with tr.span('op', i=i):\n"
+            "        junk.append([i])\n"
+            "    if len(junk) > 100:\n"
+            "        junk.clear()\n"
+            "names = [e[0] for e in tr.events()]\n"
+            "print(names.count('op'), names.count('py.gc') > 0)\n"
+        )
+        out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                             text=True, timeout=60)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.split() == ["20000", "True"]
+
+    def test_fresh_jit_records_a_compile_span(self):
+        import jax
+
+        def fresh_obs_probe(x):
+            return x * 3 + 1
+
+        tr.enable_trace(capacity=256)
+        tr.reset_trace()
+        t0 = time.perf_counter()
+        jax.jit(fresh_obs_probe)(np.float32(2)).block_until_ready()
+        comp = [e for e in tr.events() if e[0] == "jax.compile"
+                and e[4]["fun_name"] == "jit(fresh_obs_probe)"]
+        assert len(comp) == 1
+        assert t0 <= comp[0][2] and comp[0][3] > 0
+
+    def test_disabled_records_nothing_and_leaves_no_hooks(self, engine):
+        import gc
+
+        import jax
+
+        tr.enable_trace(capacity=64)
+        assert tr._on_gc in gc.callbacks
+        assert tr._on_jax_duration in _jax_listeners()
+        tr.disable_trace()
+        tr.reset_trace()
+        assert tr._on_gc not in gc.callbacks
+        assert not {tr._on_jax_duration, tr._on_jax_event} & set(_jax_listeners())
+        self._serve(engine)
+        gc.collect()
+        jax.jit(lambda x: x - 7)(np.float32(1)).block_until_ready()
+        assert tr.events() == []
+
+    def test_import_leaves_jax_out(self):
+        import subprocess
+        import sys
+
+        code = ("import sys, repro.obs; repro.obs.enable_trace(); "
+                "print('jax' in sys.modules)")
+        out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                             text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip() == "False"
+
+    def test_span_shows_on_the_profilers_host_plane(self, tmp_path):
+        import glob
+
+        import jax
+
+        tr.enable_trace(capacity=64)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with tr.span("obs.host_plane_probe"):
+                time.sleep(0.001)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+        data = jax.profiler.ProfileData.from_file(path)
+        host = [e for plane in data.planes if plane.name == "/host:CPU"
+                for line in plane.lines for e in line.events
+                if e.name == "obs.host_plane_probe"]
+        assert len(host) == 1 and host[0].duration_ns >= 1e6
 
 
 # ---------------------------------------------------------------------------

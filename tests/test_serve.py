@@ -189,3 +189,24 @@ def test_decode_state_donation():
     eng.run()
     assert len(req.tokens) == 8
     assert all(0 <= t < cfg.vocab for t in req.tokens)
+
+
+def test_per_token_stamps_and_itl():
+    """Every generated token carries the time its step synced: one stamp
+    per token, the first at ``first_token_t``, never decreasing; the
+    engine's ITL percentiles are read from their differences."""
+    cfg = get_config("gemma-2b").reduced()
+    params = init_params(cfg, jax.random.PRNGKey(9), dtype=jnp.float32)
+    eng = ContinuousBatchingEngine(cfg, params, **_GEO)
+    reqs = [_submit(eng, r) for r in _REQS]
+    eng.run()
+    gaps = []
+    for r in reqs:
+        assert len(r.token_t) == len(r.tokens) == r.max_new
+        assert r.token_t[0] == r.first_token_t
+        assert all(a <= b for a, b in zip(r.token_t, r.token_t[1:]))
+        gaps += [b - a for a, b in zip(r.token_t, r.token_t[1:])]
+    stats = eng.serve_stats()
+    assert stats["itl_p50_ms"] == pytest.approx(np.percentile(gaps, 50) * 1e3)
+    assert stats["itl_p95_ms"] == pytest.approx(np.percentile(gaps, 95) * 1e3)
+    assert not any(k.startswith("tpot") for k in stats)
