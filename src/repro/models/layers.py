@@ -233,52 +233,48 @@ def attention_chunked(cfg: ModelConfig, p, x, positions, blk: int = 2048):
 
 
 @jax.named_scope("kv_write")
-def _kv_write(cfg: ModelConfig, cache_k, cache_v, k, v, pos):
-    """This step's K/V rows written into the (B, S_max, KH, Dh) cache at
-    ``pos`` (a scalar, or one position per row)."""
-    h, kh = cfg.n_heads, cfg.n_kv_heads
-    if pos.ndim == 1:
-        # per-slot positions: each row writes its own cache index — a
-        # batched dynamic_update_slice does not exist, the row-wise
-        # iota-select is the batched form of the GQA path below
-        sel = (
-            jnp.arange(cache_k.shape[1], dtype=jnp.int32)[None, :] == pos[:, None]
-        )[:, :, None, None]
-        cache_k = jnp.where(sel, k.astype(cache_k.dtype), cache_k)
-        cache_v = jnp.where(sel, v.astype(cache_v.dtype), cache_v)
-    elif kh != h:
-        # GQA: iota-select cache update — with the cache sequence-sharded,
-        # dynamic_update_slice made GSPMD "involuntarily rematerialize"
-        # (replicate) the cache; the select touches only local shards,
-        # trading an HBM rewrite (~1 ms) for ~20 ms of measured ICI
-        sel = (
-            jnp.arange(cache_k.shape[1], dtype=jnp.int32) == pos
-        )[None, :, None, None]
-        cache_k = jnp.where(sel, k.astype(cache_k.dtype), cache_k)
-        cache_v = jnp.where(sel, v.astype(cache_v.dtype), cache_v)
-    else:
-        # kv==heads: the slice update never triggered the pathology and
-        # avoids the full-cache rewrite (measured 0.1 vs 0.9 G/dev link)
-        cache_k = jax.lax.dynamic_update_slice_in_dim(
-            cache_k, k.astype(cache_k.dtype), pos, axis=1
+def write_kv_rows(cache, rows, pos):
+    """This step's K/V rows written into a stacked (L, B, S_max, KH, Dh)
+    cache at ``(l, b, pos[b])`` for every l; rows: (L, B, KH, Dh) in the
+    cache dtype, pos: () one position for every row, or (B,) one per row.
+
+    A scalar ``pos`` is one ``dynamic_update_slice`` over the whole
+    batch; per-row positions take one per slot over all layers (B is
+    static).  On a donated cache each updates in place, where a per-slot
+    select would rewrite the whole cache.  A slot the serve engine has
+    finished at ``max_seq`` keeps ``pos[b] == S_max``: the update's start
+    is clamped, so it writes its own row at ``S_max - 1``.  Nothing reads
+    a finished slot's cache, and a slot admitted again writes every
+    position before it reads it, so that write is never seen."""
+    pos = jnp.asarray(pos, dtype=jnp.int32)
+    if pos.ndim == 0:
+        return jax.lax.dynamic_update_slice(
+            cache, rows[:, :, None], (0, 0, pos, 0, 0)
         )
-        cache_v = jax.lax.dynamic_update_slice_in_dim(
-            cache_v, v.astype(cache_v.dtype), pos, axis=1
+    for b in range(cache.shape[1]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, rows[:, b, None, None], (0, b, pos[b], 0, 0)
         )
-    return cache_k, cache_v
+    return cache
 
 
 @jax.named_scope("attention")
 def attention_decode(cfg: ModelConfig, p, x, cache_k, cache_v, pos):
-    """One-token decode against a KV cache.
+    """One-token decode against a KV cache that it only reads.
 
     x: (B, 1, D); cache_k/v: (B, S_max, KH, Dh); pos: () current index, or
     (B,) per-row positions (continuous batching: every serve slot decodes
-    at its own depth).  Returns (out, new_k, new_v)."""
+    at its own depth).  Each row's query attends over its cache at
+    ``t < pos[b]`` plus this step's own K/V as one more column.  With
+    the K/V rounded to the cache dtype this equals writing the row at
+    ``pos[b]`` and attending over ``t <= pos[b]``, up to summation order.
+    Returns (out, k, v): k/v are this step's (B, KH, Dh) rows in the cache
+    dtype, which the caller writes once after its layer scan
+    (``write_kv_rows``)."""
     b = x.shape[0]
     h, kh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    pos = jnp.asarray(pos, dtype=jnp.int32)
-    per_row = pos.ndim == 1
+    f32 = jnp.float32
+    pos = jnp.broadcast_to(jnp.asarray(pos, dtype=jnp.int32), (b,))
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].reshape(cfg.d_model, h, dh))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].reshape(cfg.d_model, kh, dh))
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"].reshape(cfg.d_model, kh, dh))
@@ -286,33 +282,38 @@ def attention_decode(cfg: ModelConfig, p, x, cache_k, cache_v, pos):
         q = q + p["bq"].reshape(h, dh)
         k = k + p["bk"].reshape(kh, dh)
         v = v + p["bv"].reshape(kh, dh)
-    posb = pos[:, None] if per_row else jnp.full((b, 1), pos, dtype=jnp.int32)
+    posb = pos[:, None]
     if cfg.pos_embedding == "mrope":
         posb = jnp.broadcast_to(posb[None], (3, b, 1))
     q = _rotate(cfg, q, posb)
-    k = _rotate(cfg, k, posb)
+    k = _rotate(cfg, k, posb)[:, 0].astype(cache_k.dtype)
+    v = v[:, 0].astype(cache_v.dtype)
 
-    cache_k, cache_v = _kv_write(cfg, cache_k, cache_v, k, v, pos)
-
-    # grouped-query einsum: repeating KV heads (broadcast_in_dim) made
-    # GSPMD all-gather the seq-sharded cache every layer (90% of decode
-    # link bytes); the grouped form contracts against the cache in its
-    # own head layout, so the T-sharded logits reduce with tiny stat ARs
-    group = h // kh
-    qg = q.reshape(b, 1, kh, group, dh)
+    # grouped-query einsum: the query's heads grouped per KV head contract
+    # against the cache in its own head layout, with no repeated KV heads
+    qg = q.reshape(b, 1, kh, h // kh, dh)
     logits = jnp.einsum("bskgd,btkd->bkgst", qg, cache_k) / np.sqrt(dh)
+    own = jnp.einsum("bskgd,bkd->bkgs", qg, k)[..., None] / np.sqrt(dh)
     if cfg.attn_logit_softcap:
         c = cfg.attn_logit_softcap
         logits = c * jnp.tanh(logits / c)
-    smax = cache_k.shape[1]
-    if per_row:
-        valid = (jnp.arange(smax)[None, :] <= pos[:, None])[:, None, None, None, :]
-    else:
-        valid = (jnp.arange(smax) <= pos)[None, None, None, None, :]
-    logits = jnp.where(valid, logits, jnp.finfo(logits.dtype).min)
-    w = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(x.dtype)
-    out = jnp.einsum("bkgst,btkd->bskgd", w, cache_v).reshape(b, 1, h * dh)
-    return out @ p["wo"], cache_k, cache_v
+        own = c * jnp.tanh(own / c)
+    past = jnp.arange(cache_k.shape[1])[None, :] < pos[:, None]
+    logits = jnp.where(
+        past[:, None, None, None, :], logits, jnp.finfo(logits.dtype).min
+    ).astype(f32)
+    own = own.astype(f32)
+    # the softmax over the cache's columns and the own column, as
+    # jax.nn.softmax computes it over their concatenation
+    m = jnp.maximum(logits.max(axis=-1, keepdims=True), own)
+    e, e_own = jnp.exp(logits - m), jnp.exp(own - m)
+    den = e.sum(axis=-1, keepdims=True) + e_own
+    w, w_own = (e / den).astype(x.dtype), (e_own / den).astype(x.dtype)
+    out = jnp.einsum(
+        "bkgst,btkd->bskgd", w, cache_v, preferred_element_type=f32
+    ) + jnp.einsum("bkgst,bkd->bskgd", w_own, v, preferred_element_type=f32)
+    out = out.astype(jnp.result_type(x.dtype, cache_v.dtype))
+    return out.reshape(b, 1, h * dh) @ p["wo"], k, v
 
 
 # ---------------------------------------------------------------------------

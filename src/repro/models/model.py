@@ -27,6 +27,7 @@ from .layers import (
     ffn_param_shapes,
     positions_for,
     rms_norm,
+    write_kv_rows,
 )
 from .mamba2 import (
     mamba2_block,
@@ -484,7 +485,9 @@ def prefill_forward(cfg: ModelConfig, params, tokens, lengths,
 def decode_step(cfg: ModelConfig, params, state, tokens, pos, moe_cap=None):
     """One decode step.  tokens: (B, 1) int32; pos: () int32 current index
     or (B,) per-slot positions (continuous batching).  ``moe_cap``
-    overrides MoE expert capacity (serving passes drop-free B*k).
+    overrides MoE expert capacity (serving passes drop-free B*k).  The
+    layer scan only reads the stacked KV caches and returns each layer's
+    new rows, written in place after it (``write_kv_rows``).
     Returns (logits (B, V) float32, new state)."""
     x = params["embed"][tokens]
     if cfg.embed_scale:
@@ -510,7 +513,8 @@ def decode_step(cfg: ModelConfig, params, state, tokens, pos, moe_cap=None):
         x, (nk, nv) = jax.lax.scan(
             body, x, (params["layers"], state["k"], state["v"]), unroll=scan_unroll()
         )
-        new_state = {"k": nk, "v": nv}
+        new_state = {"k": write_kv_rows(state["k"], nk, pos),
+                     "v": write_kv_rows(state["v"], nv, pos)}
 
     elif cfg.family == "ssm":
 
@@ -564,7 +568,9 @@ def decode_step(cfg: ModelConfig, params, state, tokens, pos, moe_cap=None):
             (params["layers"], state["conv"], state["ssm"], state["k"], state["v"]),
             unroll=scan_unroll(),
         )
-        new_state = {"conv": nconv, "ssm": nssm, "k": nk, "v": nv}
+        new_state = {"conv": nconv, "ssm": nssm,
+                     "k": write_kv_rows(state["k"], nk, pos),
+                     "v": write_kv_rows(state["v"], nv, pos)}
     else:
         raise ValueError(cfg.family)
 

@@ -7,6 +7,11 @@ the invariant that validates KV caches (dense/moe), recurrent WKV state
 against their sequential decode twins.
 """
 
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -20,12 +25,14 @@ from repro.models import (
     init_params,
     model_forward,
 )
+from repro.models.model import decode_state_batch_dims
 from repro.serve import (
     ContinuousBatchingEngine,
     QueueFull,
     ServeEngine,
     make_prefill_step,
 )
+from repro.serve.engine import init_carry, make_decode_step
 
 FAMILY_REP = {
     "dense": "qwen2-7b",        # GQA + qkv bias + rope
@@ -55,6 +62,153 @@ def test_decode_matches_forward(arch):
             atol=2e-4,
             err_msg=f"{arch}: decode diverges from forward at position {t}",
         )
+
+
+# the serve path's configurations: each family, the chat cell's model
+# (multi-head, kh == h, as published) and a softcapped variant
+_PER_ROW = {
+    **{a: get_config(a).reduced() for a in FAMILY_REP.values()},
+    "minicpm-2b": get_config("minicpm-2b").reduced(n_kv_heads=4),
+    "qwen2-7b-softcap": get_config("qwen2-7b").reduced(attn_logit_softcap=5.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_PER_ROW))
+def test_per_row_decode_matches_forward(name):
+    """The serve engine's path: one position per slot, the slots at
+    staggered depths.  Each slot is first stepped alone to its depth, the
+    four states are joined along the batch axis, and then every step
+    decodes all slots at once; each slot's logits must match the forward
+    pass at its own position."""
+    cfg = _PER_ROW[name]
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    offsets = np.array([0, 1, 3, 5])
+    B, max_seq = len(offsets), 16  # the chunked scans take multiples of 8
+    S = max_seq - int(offsets.max())
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, max_seq), 0, cfg.vocab)
+    # one forward per row: a row alone never overflows an expert's capacity
+    full_logits = jnp.concatenate(
+        [model_forward(cfg, params, tokens=tokens[b:b + 1])[0] for b in range(B)]
+    )
+    step = jax.jit(lambda p, st, t, i: decode_step(cfg, p, st, t, i))
+
+    def check(logits, rows, at):
+        np.testing.assert_allclose(
+            logits, full_logits[rows, at], rtol=2e-4, atol=2e-4,
+            err_msg=f"{name}: per-row decode diverges from forward at {at}",
+        )
+
+    alone = []
+    for b, off in enumerate(offsets):
+        st = init_decode_state(cfg, 1, max_seq=max_seq, dtype=jnp.float32)
+        for t in range(off):
+            logits, st = step(params, st, tokens[b:b + 1, t:t + 1],
+                              jnp.array([t], jnp.int32))
+            check(logits, [b], [t])
+        alone.append(st)
+    dims = decode_state_batch_dims(cfg)
+    state = {n: jnp.concatenate([st[n] for st in alone], axis=dims[n])
+             for n in dims}
+    rows = np.arange(B)
+    for t in range(S):
+        pos = offsets + t
+        logits, state = step(params, state, tokens[rows, pos][:, None],
+                             jnp.asarray(pos, jnp.int32))
+        check(logits, rows, pos)
+
+
+@pytest.mark.parametrize("arch", ["minicpm-2b", "qwen2-7b", "zamba2-2.7b"])
+def test_decode_step_writes_one_kv_row_per_slot(arch):
+    """One jitted, donated engine decode step changes the K/V stacks at
+    ``(l, b, pos[b])`` for every layer l and slot b, and nowhere else:
+    not at a live slot's earlier positions, not past the last position
+    (a slot at ``max_seq - 1``), and a finished slot writes only its own
+    row, also one finished at ``max_seq``, whose write is clamped onto
+    its last row."""
+    cfg = get_config(arch).reduced()
+    params = init_params(cfg, jax.random.PRNGKey(10), dtype=jnp.float32)
+    slots, max_seq = 5, 16
+    carry = init_carry(cfg, slots, max_seq, jnp.float32)
+    keys = iter(jax.random.split(jax.random.PRNGKey(11), 8))
+    carry["state"] = {
+        n: jax.random.normal(next(keys), a.shape, a.dtype)
+        for n, a in carry["state"].items()
+    }
+    pos = np.array([5, max_seq - 1, 9, 0, max_seq], np.int32)
+    # slot 2 has finished, slot 4 finished at max_seq
+    active = np.array([True, True, False, True, False])
+    carry.update(
+        tokens=jnp.array([[3], [7], [11], [2], [5]], jnp.int32),
+        pos=jnp.asarray(pos), active=jnp.asarray(active),
+        budget=jnp.full((slots,), 100, jnp.int32),
+    )
+    before = {n: np.asarray(carry["state"][n]) for n in ("k", "v")}
+    step = jax.jit(make_decode_step(cfg, slots, max_seq), donate_argnums=(1,))
+    new, out = step(params, carry)
+    assert np.asarray(out)[1].tolist() == active.astype(int).tolist()
+
+    written = np.zeros(before["k"].shape[:3], bool)  # (L, B, S)
+    written[:, np.arange(slots), np.minimum(pos, max_seq - 1)] = True
+    for n in ("k", "v"):
+        changed = np.any(np.asarray(new["state"][n]) != before[n], axis=(3, 4))
+        np.testing.assert_array_equal(
+            changed, written, err_msg=f"{arch}: {n} changed off (l, b, pos[b])"
+        )
+        for b in np.flatnonzero(active):
+            assert not changed[:, b, : pos[b]].any(), (
+                f"{arch}: slot {b}'s earlier {n} positions changed"
+            )
+
+
+# compiled in a child: the host device count is fixed when the CPU
+# backend starts
+_MESH_DECODE = """
+import sys
+import jax, jax.numpy as jnp
+from repro.configs import get_config
+from repro.dist.sharding import param_shardings, serve_carry_shardings
+from repro.launch.dryrun import collective_link_bytes
+from repro.launch.mesh import make_local_mesh
+from repro.models.model import abstract_params
+from repro.serve.engine import init_carry, make_decode_step
+
+cfg = get_config(sys.argv[1]).reduced()
+mesh, slots, max_seq = make_local_mesh(data=4), 4, 64
+carry = jax.eval_shape(lambda: init_carry(cfg, slots, max_seq))
+csh = serve_carry_shardings(cfg, mesh, slots, max_seq)
+step = jax.jit(make_decode_step(cfg, slots, max_seq),
+               in_shardings=(param_shardings(cfg, mesh), csh),
+               out_shardings=(csh, None), donate_argnums=(1,))
+hlo = step.lower(abstract_params(cfg, jnp.bfloat16), carry).compile().as_text()
+print(collective_link_bytes(hlo, 4)["bytes"]["total"])
+"""
+
+
+@pytest.mark.parametrize("arch", ["minicpm-2b", "qwen2-7b", "zamba2-2.7b"])
+def test_mesh_decode_moves_only_kv_rows(arch):
+    """The engine's decode step on four host devices with the carry
+    batch-sharded (``serve_carry_shardings``): the per-slot row writes
+    move each slot's new K/V rows and position between devices, and
+    nothing the size of a cache.  Bound: three times the step's K and V
+    rows at 4 bytes a value (the CPU moves them in float32; 2.3 times
+    when this test was written), under a tenth of the stacked caches."""
+    cfg = get_config(arch).reduced()
+    k = jax.eval_shape(lambda: init_decode_state(cfg, 4, 64))["k"]
+    L, B, S, KH, Dh = k.shape
+    rows = 2 * L * B * KH * Dh * 4
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join(
+                   [src, *filter(None, [os.environ.get("PYTHONPATH")])]))
+    out = subprocess.run([sys.executable, "-c", _MESH_DECODE, arch], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    moved = float(out.stdout.split()[-1])
+    assert moved <= 3 * rows, (
+        f"{arch}: decode moves {moved:.0f} link bytes a device, "
+        f"over 3x the step's K/V rows ({rows} bytes)"
+    )
 
 
 def test_prefill_last_only_matches_forward():
